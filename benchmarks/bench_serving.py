@@ -756,12 +756,26 @@ def check_baseline(result: dict, baseline_path: str,
     return failures
 
 
+def _require_cpu_backend(lane) -> None:
+    """The elastic, fleet and telemetry-scale lanes run CPU VREs on forced
+    host devices. A process whose backend is an accelerator refuses them
+    rather than quietly measuring them on the CPU."""
+    import jax
+
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        raise RuntimeError(
+            f"lane {lane} runs on forced CPU host devices, but this process "
+            f"serves from {platform!r}; run it under JAX_PLATFORMS=cpu")
+
+
 def _elastic(fast: bool) -> dict:
     """VRE serving plane driven through two load waves with a mesh resize
     applied at the inter-wave safe point. 100% of submitted requests must
     complete; the report carries resize downtime and before/after tok/s."""
     import jax
 
+    _require_cpu_backend("--elastic")
     if len(jax.devices()) < 2:
         if os.environ.get("REPRO_ELASTIC_CHILD"):
             raise RuntimeError(
@@ -810,6 +824,7 @@ def _forced_devices_subprocess(extra_args, fast: bool,
     """Re-exec this benchmark with forced host devices and the given entry
     flags, returning its JSON report (the parent process already
     initialized its backend, usually with a single device)."""
+    _require_cpu_backend(extra_args)
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{n_devices}")

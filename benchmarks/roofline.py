@@ -13,7 +13,8 @@ import glob
 import json
 from pathlib import Path
 
-DRYRUN = Path("/root/repo/experiments/dryrun_v2")
+EXPERIMENTS = Path(__file__).resolve().parents[1] / "experiments"
+DRYRUN = EXPERIMENTS / "dryrun_v2"
 
 
 def _advice(row):
@@ -77,7 +78,7 @@ def to_markdown(rows) -> str:
 def main(fast: bool = False):
     rows = load_rows()
     md = to_markdown(rows)
-    out = Path("/root/repo/experiments/roofline.md")
+    out = EXPERIMENTS / "roofline.md"
     out.write_text(md + "\n")
     doms = {}
     for r in rows:

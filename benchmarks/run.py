@@ -9,7 +9,7 @@ import sys
 import time
 from pathlib import Path
 
-OUT = Path("/root/repo/experiments/bench")
+OUT = Path(__file__).resolve().parents[1] / "experiments" / "bench"
 
 
 def _run(name, fn, derived_fn, fast):

@@ -252,7 +252,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser(prog="repro.cli")
     sub = ap.add_subparsers(dest="cmd", required=True)
     p = sub.add_parser("init")
-    p.add_argument("provider", choices=["cpu", "tpu-v5e"])
+    p.add_argument("provider", choices=["cpu", "tpu-v5e"],
+                   help="devices the VRE procures: the host CPU, or TPU v5e "
+                        "chips (apply fails where JAX finds none)")
     p.add_argument("directory")
     p.set_defaults(fn=cmd_init)
     p = sub.add_parser("apply")
@@ -377,6 +379,8 @@ def main(argv=None):
     p.add_argument("--dir", required=True)
     p.set_defaults(fn=cmd_destroy)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     args.fn(args)
 
 

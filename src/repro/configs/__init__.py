@@ -1,4 +1,4 @@
 from repro.configs.base import (  # noqa: F401
-    ARCHS, SHAPES, ModelConfig, MoEConfig, SSMConfig, ShapeConfig,
-    all_cells, get_config, get_shape, reduced,
+    ARCHS, SHAPES, ModelConfig, MoEConfig, ServedCut, SSMConfig, ShapeConfig,
+    all_cells, get_config, get_shape, reduced, served_config, served_cut,
 )

@@ -1,9 +1,10 @@
 """Config system: model/shape/mesh/train dataclasses + the architecture registry.
 
 Every assigned architecture is a frozen ``ModelConfig`` (hashable, usable as a
-static jit argument). ``reduced()`` derives the family-preserving smoke-test
-variant; the full configs are only ever lowered via the dry-run
-(ShapeDtypeStruct, no allocation).
+static jit argument) at its published sizes. ``served_config()`` resolves what
+the serving plane runs on a platform: ``reduced()``, the family-preserving
+tiny variant, on the CPU; on a chip, the one-chip cut (``ServedCut``) that the
+architecture's config file records, with the published widths intact.
 """
 from __future__ import annotations
 
@@ -226,6 +227,41 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     return dataclasses.replace(cfg, name=cfg.name + "-reduced", **changes)
 
 
+@dataclasses.dataclass(frozen=True)
+class ServedCut:
+    """One chip's share of a stated deployment (model-configs convention):
+    the served ``config`` keeps every published width; ``reduced`` names
+    each key cut from the published config (key -> "published -> served:
+    why"), ``assumed`` each size not taken from the source, ``deployment``
+    the chips the whole model would occupy and what this one holds, and
+    ``chips_per_layer`` how many chips share one layer."""
+    config: ModelConfig
+    source: str
+    reduced: dict
+    assumed: dict
+    deployment: str
+    chips_per_layer: int = 1
+
+
+def served_cut(arch: str) -> ServedCut:
+    mod = importlib.import_module(f"repro.configs.{_module(arch)}")
+    cut = getattr(mod, "SERVED", None)
+    if cut is None:
+        raise ValueError(f"{arch} records no one-chip serving cut (SERVED in "
+                         f"repro/configs/{_MODULE_FOR[arch]}.py); it can only "
+                         f"be served reduced, on the CPU")
+    return cut
+
+
+def served_config(arch: str, platform: str) -> ModelConfig:
+    """The config the serving plane runs for ``arch`` on ``platform``
+    (``jax.Device.platform``): the reduced variant on the CPU, the recorded
+    one-chip cut anywhere else."""
+    if platform == "cpu":
+        return reduced(get_config(arch))
+    return served_cut(arch).config
+
+
 # ---------------------------------------------------------------------------
 # Registry
 # ---------------------------------------------------------------------------
@@ -257,11 +293,14 @@ _MODULE_FOR = {
 }
 
 
-def get_config(arch: str) -> ModelConfig:
+def _module(arch: str) -> str:
     if arch not in _MODULE_FOR:
         raise KeyError(f"unknown arch {arch!r}; known: {sorted(_MODULE_FOR)}")
-    mod = importlib.import_module(f"repro.configs.{_MODULE_FOR[arch]}")
-    return mod.CONFIG
+    return _MODULE_FOR[arch]
+
+
+def get_config(arch: str) -> ModelConfig:
+    return importlib.import_module(f"repro.configs.{_module(arch)}").CONFIG
 
 
 def get_shape(name: str) -> ShapeConfig:

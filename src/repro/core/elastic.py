@@ -102,13 +102,15 @@ def resize_serving(vre, service: str = "lm-server") -> Optional[dict]:
 
     import jax
 
+    from repro.core.vre import PROVIDER_PLATFORMS
+
     if vre.pending_resize is None:
         return None
     need = int(np.prod(vre.pending_resize))
     # fleet-arbitrated VREs resize within their granted slice of the shared
     # pool, not against the whole provider
     have = (len(vre.device_pool) if vre.device_pool is not None
-            else len(jax.devices()))
+            else len(jax.devices(PROVIDER_PLATFORMS[vre.config.provider])))
     if have < need:
         vre.monitor.log("vre", "resize_infeasible",
                         want=need, have=have,

@@ -13,12 +13,13 @@ import jax
 import numpy as np
 
 from repro.checkpoint.store import CheckpointStore
-from repro.configs import get_config, reduced
+from repro.configs import served_config
 from repro.core.registry import ServiceHandle, register_service
 from repro.core.scheduler import ClusterScheduler
+from repro.core.vre import PROVIDER_PLATFORMS
 from repro.core.workflow import Workflow
 from repro.data.pipeline import DataConfig, SyntheticLMData
-from repro.models.model import build_model
+from repro.models.model import build_model, init_params
 from repro.optim.adamw import OptimizerConfig
 from repro.serving.autoscaler import Autoscaler, AutoscalerConfig
 from repro.serving.engine import EdgeRouter, ServingEngine
@@ -30,11 +31,8 @@ from repro.training.train_step import (TrainStepConfig, init_state,
 
 
 def _model_cfg(ctx):
-    arch = ctx.config.arch or "yi-9b"
-    cfg = get_config(arch)
-    if ctx.config.provider == "cpu":
-        cfg = reduced(cfg)
-    return cfg
+    return served_config(ctx.config.arch or "yi-9b",
+                         PROVIDER_PLATFORMS[ctx.config.provider])
 
 
 _SERVED_MODEL_CACHE: dict = {}
@@ -50,14 +48,20 @@ def _served_model(ctx):
     possible moment (right after the resize, under the very load that
     triggered it). Keyed by what ``_model_cfg`` derives the config from;
     params are deterministic (fixed seed), so sharing them across VREs of
-    the same arch is observationally identical to rebuilding."""
+    the same arch is observationally identical to rebuilding.
+
+    The params are built on the mesh's first device, the one the first
+    replica serves from: its engine then aliases them instead of holding a
+    second copy."""
     key = (ctx.config.arch or "yi-9b", ctx.config.provider)
     with _SERVED_MODEL_LOCK:
         ent = _SERVED_MODEL_CACHE.get(key)
         if ent is None:
             cfg = _model_cfg(ctx)
             model = build_model(cfg)
-            params, _ = model.init(jax.random.PRNGKey(0))
+            device = ctx.mesh.devices.flat[0] if ctx.mesh is not None \
+                else None
+            params = init_params(model, jax.random.PRNGKey(0), device)
             ent = (cfg, model, params)
             _SERVED_MODEL_CACHE[key] = ent
     return ent

@@ -35,6 +35,10 @@ from repro.core.registry import (EndpointDirectory, Service, ServiceHandle,
                                  ServiceRegistry, GLOBAL_REGISTRY)
 
 
+# the jax platform whose devices each provider procures
+PROVIDER_PLATFORMS = {"cpu": "cpu", "tpu-v5e": "tpu"}
+
+
 @dataclasses.dataclass
 class VREConfig:
     name: str
@@ -43,7 +47,7 @@ class VREConfig:
     services: List[str] = dataclasses.field(default_factory=list)
     arch: Optional[str] = None
     shape: Optional[str] = None           # input-shape preset for lm services
-    provider: str = "cpu"                 # cpu | tpu-v5e (dry-run)
+    provider: str = "cpu"                 # a key of PROVIDER_PLATFORMS
     workdir: str = "/tmp/vre"
     storage_servers: int = 4
     extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
@@ -105,9 +109,11 @@ class VirtualResearchEnvironment:
 
     # -- infrastructure layer ---------------------------------------------
     def _procure_mesh(self) -> Mesh:
+        """The provider's devices only: a ``tpu-v5e`` VRE where JAX has no
+        TPU fails here rather than serving from the CPU."""
         n = int(np.prod(self.config.mesh_shape))
         devices = (self.device_pool if self.device_pool is not None
-                   else jax.devices())
+                   else jax.devices(PROVIDER_PLATFORMS[self.config.provider]))
         if len(devices) < n:
             raise RuntimeError(
                 f"provider has {len(devices)} devices, VRE wants {n}")

@@ -18,8 +18,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import shard_map
-
 
 def quantize_int8(g, scale=None):
     """Per-tensor symmetric int8 quantization. Returns (q, scale)."""
@@ -80,7 +78,7 @@ def compressed_pod_psum(grads, residuals, mesh, pod_axis: str = "pod"):
     # shard_map over the pod axis only; other axes stay as-is (auto)
     from jax.sharding import PartitionSpec as P
     spec = jax.tree.map(lambda _: P(), grads)
-    out = shard_map(
+    out = jax.shard_map(
         mapped, mesh=mesh,
         in_specs=(spec, spec), out_specs=(spec, spec),
         axis_names={pod_axis}, check_vma=False,

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
-
 
 def pipeline_forward(mesh, pp_axis: str, body: Callable, stage_params,
                      x_micro, *, layers_per_stage: int):
@@ -80,7 +78,7 @@ def pipeline_forward(mesh, pp_axis: str, body: Callable, stage_params,
     # fully-manual region (no axis_names subset): partially-auto shard_map
     # lowers axis_index through PartitionId, which the SPMD partitioner in
     # the installed XLA rejects; in a fully-manual region it is supported
-    return shard_map(
+    return jax.shard_map(
         staged, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
         check_vma=False,

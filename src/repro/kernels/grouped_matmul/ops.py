@@ -1,4 +1,4 @@
-"""Jitted grouped-matmul wrapper with CPU-interpret fallback."""
+"""Jitted grouped-matmul wrapper; ``interpret=True`` (CPU tests) is explicit."""
 from __future__ import annotations
 
 import functools
@@ -11,8 +11,6 @@ from repro.kernels.grouped_matmul.kernel import grouped_matmul_kernel
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f", "block_d",
                                              "interpret"))
 def grouped_matmul(x, w, *, block_c=128, block_f=128, block_d=256,
-                   interpret=None):
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
+                   interpret=False):
     return grouped_matmul_kernel(x, w, block_c=block_c, block_f=block_f,
-                                 block_d=block_d, interpret=interp)
+                                 block_d=block_d, interpret=interpret)

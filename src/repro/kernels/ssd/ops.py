@@ -10,19 +10,15 @@ import jax.numpy as jnp
 from repro.kernels.ssd.kernel import ssd_intra_chunk
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_chunked_pallas(x, dt, A, B, C, chunk: int, interpret=None):
-    """x: (b,s,nh,hd); dt: (b,s,nh); A: (nh,); B/C: (b,s,ds)."""
+def ssd_chunked_pallas(x, dt, A, B, C, chunk: int, interpret=False):
+    """x: (b,s,nh,hd); dt: (b,s,nh); A: (nh,); B/C: (b,s,ds).
+    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU)."""
     b, s, nh, hd = x.shape
     ds = B.shape[-1]
     assert s % chunk == 0
     nc = s // chunk
     f32 = jnp.float32
-    interp = (not _on_tpu()) if interpret is None else interpret
 
     dtc = dt.reshape(b, nc, chunk, nh).astype(f32)
     a = (dtc * A).transpose(0, 3, 1, 2)                      # (b,nh,nc,c)
@@ -31,10 +27,10 @@ def ssd_chunked_pallas(x, dt, A, B, C, chunk: int, interpret=None):
     Bc = B.reshape(b, nc, chunk, ds)
     Cc = C.reshape(b, nc, chunk, ds)
 
-    y_intra, s_loc = ssd_intra_chunk(a, xdt, Bc, Cc, interpret=interp)
+    acs = jnp.cumsum(a, axis=-1)                             # (b,nh,nc,c)
+    y_intra, s_loc = ssd_intra_chunk(acs, xdt, Bc, Cc, interpret=interpret)
 
     # inter-chunk recurrence (cheap): S_n = dec_n * S_{n-1} + S_n_local
-    acs = jnp.cumsum(a, axis=-1)                             # (b,nh,nc,c)
     chunk_decay = jnp.exp(acs[..., -1])                      # (b,nh,nc)
     s0 = jnp.zeros((b, nh, ds, hd), f32)
 

@@ -27,14 +27,11 @@ from pathlib import Path
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.xla_cache")
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-
 from repro.configs.base import SHAPES, get_config, all_cells  # noqa: E402
 from repro.launch import hlo_analysis, mesh as mesh_lib, specs  # noqa: E402
+from repro.launch.compile_cache import CHECKOUT, configure_compile_cache  # noqa: E402
 
-OUT_DIR = Path("/root/repo/experiments/dryrun")
+OUT_DIR = CHECKOUT / "experiments" / "dryrun"
 
 
 def build_step(cfg, shape, mesh, policy, parallel, model, aux,
@@ -151,9 +148,12 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         model_flops_global = 2.0 * n_active * tokens
     model_flops_dev = model_flops_global / chips
 
-    compute_s = stats.flops / mesh_lib.PEAK_FLOPS_BF16
-    memory_s = (stats.hbm_bytes_tpu or stats.hbm_bytes) / mesh_lib.HBM_BW
-    coll_s = stats.coll_wire_bytes / mesh_lib.ICI_LINK_BW
+    # the roofline is the production chip's (the placeholder CPU devices
+    # this compiles on have no peaks of their own)
+    peaks = mesh_lib.chip_peaks(mesh_lib.PRODUCTION_DEVICE_KIND)
+    compute_s = stats.flops / peaks.flops_bf16
+    memory_s = (stats.hbm_bytes_tpu or stats.hbm_bytes) / peaks.hbm_bw
+    coll_s = stats.coll_wire_bytes / peaks.ici_link_bw
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": coll_s}
     dominant = max(terms, key=terms.get)
@@ -179,12 +179,12 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
             "peak_hbm_per_device_bytes": hbm_per_dev,
             "cpu_upcast_buffer_bytes": stats.upcast_buffer_bytes,
             "peak_hbm_tpu_adjusted_bytes": hbm_adjusted,
-            "fits_16gb": bool(hbm_adjusted < 16e9),
-            "fits_16gb_raw_cpu": bool(hbm_per_dev < 16e9),
+            "fits_16gb": bool(hbm_adjusted < peaks.hbm_bytes),
+            "fits_16gb_raw_cpu": bool(hbm_per_dev < peaks.hbm_bytes),
         },
         "cost_analysis_raw": {"flops": ca.get("flops"),
                               "bytes_accessed": ca.get("bytes accessed")},
-        "memory_s_cpu_raw": stats.hbm_bytes / mesh_lib.HBM_BW,
+        "memory_s_cpu_raw": stats.hbm_bytes / peaks.hbm_bw,
         "hlo_stats": stats.to_json(),
         "roofline": {
             **terms,
@@ -194,7 +194,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
             "hlo_flops_per_device": stats.flops,
             "useful_flops_ratio": (model_flops_dev / stats.flops
                                    if stats.flops else None),
-            "roofline_fraction": (model_flops_dev / mesh_lib.PEAK_FLOPS_BF16
+            "roofline_fraction": (model_flops_dev / peaks.flops_bf16
                                   / max(compute_s, memory_s, coll_s)
                                   if max(compute_s, memory_s, coll_s) else None),
         },
@@ -220,6 +220,9 @@ def main():
                     help="run every runnable cell x both meshes in subprocesses")
     ap.add_argument("--out", default=str(OUT_DIR))
     args = ap.parse_args()
+    configure_compile_cache()
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     out_dir = Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)
 

@@ -9,6 +9,8 @@ Production target: TPU v5e pods, 256 chips/pod.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 
 import jax
@@ -38,7 +40,31 @@ def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     return Mesh(np.array(devices[:n]).reshape(shape), axes)
 
 
-# v5e hardware constants for the roofline (single chip)
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # bytes/s
-ICI_LINK_BW = 50e9             # bytes/s per link
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # bytes/s
+    hbm_bytes: float
+    ici_link_bw: float          # bytes/s per chip-to-chip link
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" (v5e): Google Cloud documentation, "TPU v5e" -- 197 TFLOP/s
+# bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of interconnect over 4 links.
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+                             ici_link_bw=1600e9 / 8 / 4),
+}
+
+# the chip the production meshes above are built from
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of ``device_kind``; a kind with no published entry
+    is an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(CHIP_PEAKS)}") from None

@@ -18,6 +18,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.core.monitoring import Monitor
+from repro.launch.compile_cache import configure_compile_cache
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.replica import ReplicaSet
 
@@ -182,14 +183,15 @@ def build_replicaset(arch: str, *, replicas: int, slots: int, max_seq: int,
                      draft: str = "ngram",
                      record_path: Optional[str] = None) -> ReplicaSet:
     import jax
-    from repro.configs import get_config, reduced as reduce_cfg
-    from repro.models.model import build_model
+    from repro.configs import served_config
+    from repro.models.model import build_model, init_params
     from repro.serving.prefix_cache import PrefixCache
     from repro.serving.speculative import build_draft, supports_speculation
 
-    cfg = reduce_cfg(get_config(arch))
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    cfg = served_config(arch, device.platform)
     model = build_model(cfg)
-    params, _ = model.init(jax.random.PRNGKey(0))
+    params = init_params(model, jax.random.PRNGKey(0), device)
     prefix_cache = None
     if chunk_tokens and prefix_cache_mb > 0:
         prefix_cache = PrefixCache(chunk_tokens,
@@ -200,7 +202,8 @@ def build_replicaset(arch: str, *, replicas: int, slots: int, max_seq: int,
         from repro.observability import Recorder
         recorder = Recorder(
             record_path, tenant=arch, monitor=monitor,
-            meta={"arch": arch, "provider": "cpu",
+            meta={"arch": arch, "platform": device.platform,
+                  "device_kind": device.device_kind,
                   "serving": {"replicas": replicas, "slots": slots,
                               "max_seq": max_seq,
                               "chunk_tokens": chunk_tokens,
@@ -360,6 +363,7 @@ def main(argv=None):
                          "request (enables per-request tracing)")
     args = ap.parse_args(argv)
     validate_serving_args(args, ap.error)
+    configure_compile_cache()
     args.chunk_tokens = args.chunk_tokens or 0
     args.prefix_cache_mb = args.prefix_cache_mb or 0.0
     args.speculate = args.speculate or 0
@@ -372,7 +376,7 @@ def main(argv=None):
                           speculate=args.speculate,
                           draft=args.draft or "ngram",
                           record_path=args.record)
-    vocab = rs.engines[0].cfg.vocab_size      # the (reduced) serving config
+    vocab = rs.engines[0].cfg.vocab_size      # the served config
     rs.start()
     rng = np.random.default_rng(0)
     if args.shared_prefix:

@@ -270,6 +270,16 @@ def _remat(fn, policy_name: str):
 # ---------------------------------------------------------------------------
 
 
+def init_params(model, key, device=None):
+    """Params from one jitted ``model.init``, written straight to ``device``
+    (the default device when None). Eager init would materialize each
+    stacked weight in f32 before its bf16 cast: at published widths one
+    such transient is gigabytes."""
+    sharding = (jax.sharding.SingleDeviceSharding(device)
+                if device is not None else None)
+    return jax.jit(lambda k: model.init(k)[0], out_shardings=sharding)(key)
+
+
 def _stacked_init(key, n: int, one_init):
     keys = jax.random.split(key, n)
     return jax.vmap(one_init)(keys)

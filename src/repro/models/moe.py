@@ -29,8 +29,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import dense_init, mlp_init, mlp_apply
 
-from repro.distributed.compat import shard_map
-
 
 def moe_init(key, cfg, dtype):
     m = cfg.moe
@@ -165,7 +163,7 @@ def moe_apply(params, cfg, x, mesh, parallel, capacity_factor=None):
         aux = jax.lax.pmean(aux, parallel.batch_axes) if parallel.batch_axes else aux
         return y.reshape(bl, sl, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         f, mesh=mesh,
         in_specs=(bspec, P(), wspec, wspec, wspec),
         out_specs=(bspec, P()),

@@ -1,5 +1,6 @@
-"""Per-kernel allclose vs the pure-jnp oracles, swept over shapes/dtypes
-(interpret mode executes the kernel bodies on CPU)."""
+"""Per-kernel allclose vs the pure-jnp oracles, swept over shapes/dtypes.
+Every call passes ``interpret=True``: the Pallas interpreter executes the
+kernel bodies on the CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,7 +29,7 @@ def test_flash_attention_vs_ref(s, h, kv, d, win, cap, dtype):
     kk = jax.random.normal(jax.random.PRNGKey(1), (b, s, kv, d)).astype(dtype)
     v = jax.random.normal(jax.random.PRNGKey(2), (b, s, kv, d)).astype(dtype)
     out = flash_attention(q, kk, v, window=win, softcap=cap,
-                          block_q=64, block_kv=64)
+                          block_q=64, block_kv=64, interpret=True)
     g = h // kv
     kr = jnp.repeat(kk, g, 2).transpose(0, 2, 1, 3)
     vr = jnp.repeat(v, g, 2).transpose(0, 2, 1, 3)
@@ -53,7 +54,7 @@ def test_ssd_kernel_vs_ref(s, nh, hd, ds, ch):
     A = -jnp.exp(jnp.linspace(0.0, 1.0, nh))
     B = jax.random.normal(jax.random.PRNGKey(4), (b, s, ds)) * 0.3
     C = jax.random.normal(jax.random.PRNGKey(5), (b, s, ds)) * 0.3
-    y, st = ssd_chunked_pallas(x, dt, A, B, C, chunk=ch)
+    y, st = ssd_chunked_pallas(x, dt, A, B, C, chunk=ch, interpret=True)
     yr, str_ = ssd_ref(x, dt, A, B, C)
     np.testing.assert_allclose(y, yr, atol=5e-4, rtol=5e-3)
     np.testing.assert_allclose(st, str_, atol=5e-4, rtol=5e-3)
@@ -69,7 +70,8 @@ def test_grouped_matmul_vs_ref(e, c, d, f, bc, bf, bd, dtype):
     k = jax.random.PRNGKey(0)
     x = (jax.random.normal(k, (e, c, d)) * 0.3).astype(dtype)
     w = (jax.random.normal(jax.random.PRNGKey(1), (e, d, f)) * 0.3).astype(dtype)
-    g = grouped_matmul(x, w, block_c=bc, block_f=bf, block_d=bd)
+    g = grouped_matmul(x, w, block_c=bc, block_f=bf, block_d=bd,
+                       interpret=True)
     gr = grouped_matmul_ref(x, w)
     tol = 3e-4 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(g, np.float32),

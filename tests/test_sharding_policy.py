@@ -7,9 +7,8 @@ from repro.configs import get_config, ARCHS
 from repro.distributed.sharding import (Parallelism, ShardingPolicy,
                                         attn_mode, padded_heads)
 
-# AbstractMesh takes (name, size) pairs in the installed JAX
-MESH_1POD = AbstractMesh((("data", 16), ("model", 16)))
-MESH_2POD = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _policy(arch, kind="train", mesh=MESH_1POD):

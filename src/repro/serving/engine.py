@@ -21,6 +21,14 @@ for requests already decoding. Chunk boundaries feed an optional
 cross-request ``PrefixCache`` (see ``repro.serving.prefix_cache``): requests
 sharing a prompt head restore the deepest cached boundary and recompute only
 their tail.
+
+Every program is a named jit (``serve_decode``, ``serve_prefill``,
+``serve_chunk``, ...), and each phase of the loop is a profiler annotation
+(``serve.admit``, ``serve.prefill``, ``serve.chunk``, ``serve.decode``,
+``serve.fetch``, ``serve.emit``, ``serve.wait``; leaves, none inside
+another), so a device trace tells the programs apart and names what the
+host did in each idle gap. With no trace running, an annotation costs the
+profiler's inactive check.
 """
 from __future__ import annotations
 
@@ -34,6 +42,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.observability.tracing import NULL_TRACE, TraceContext, next_rid
 
@@ -157,7 +166,17 @@ class ServingEngine:
                         "prefill_tokens": 0, "prefix_hit_tokens": 0,
                         "prefill_chunk_batches": 0, "spec_steps": 0,
                         "spec_proposed": 0, "spec_accepted": 0,
-                        "spec_emitted": 0}
+                        "spec_emitted": 0,
+                        # token positions prefill programs compute for
+                        # prompts (pad rows and pad columns included), and
+                        # the real prompt positions among them
+                        "prefill_positions_computed": 0,
+                        "prefill_positions_real": 0,
+                        # host time from a step's token fetch to the loop's
+                        # next program call, waits excluded: the device has
+                        # nothing queued meanwhile
+                        "host_gap_s": 0.0}
+        self._fetched_t: Optional[float] = None
         # jitted prefill/decode are shared across all engines with the same
         # (model, slots, max_seq): replicas and failover respawns then reuse
         # one compile instead of paying it per replica. Prefill is jitted
@@ -169,9 +188,14 @@ class ServingEngine:
             model._engine_jit_cache = jit_cache
         key = (slots, max_seq)
         if key not in jit_cache:
-            jit_cache[key] = (
-                jax.jit(lambda p, c, t, pos: model.decode(p, c, t, pos)),
-                jax.jit(lambda p, t: model.prefill(p, t, max_seq)[1]))
+            # named functions, so each program keeps one name in a device
+            # trace (``jit_serve_decode``, ...)
+            def serve_decode(p, c, t, pos):
+                return model.decode(p, c, t, pos)
+
+            def serve_prefill(p, t):
+                return model.prefill(p, t, max_seq)[1]
+            jit_cache[key] = (jax.jit(serve_decode), jax.jit(serve_prefill))
         self._decode, self._prefill = jit_cache[key]
         self._pad_ok = _padding_safe(model, max_seq)
         # chunked prefill is exact only where padded prefill is (all-global
@@ -188,7 +212,7 @@ class ServingEngine:
         if self._chunk_ok:
             ckey = (slots, max_seq, self.chunk_tokens)
             if ckey not in jit_cache:
-                def chunk_fn(p, cache, toks, pos0, slot):
+                def serve_chunk(p, cache, toks, pos0, slot):
                     # slice one slot out of the batched cache, run the chunk
                     # against it, scatter the updated slice back — slot and
                     # pos0 are traced, so one compile serves every slot and
@@ -201,7 +225,7 @@ class ServingEngine:
                         lambda full, s:
                         jax.lax.dynamic_update_slice_in_dim(full, s, slot, 1),
                         cache, new_sl)
-                jit_cache[ckey] = jax.jit(chunk_fn)
+                jit_cache[ckey] = jax.jit(serve_chunk)
             self._chunk = jit_cache[ckey]
             # batched variant: when several slots are mid-chunking, gather
             # each one's cache slice into a batch row and advance them all
@@ -212,14 +236,14 @@ class ServingEngine:
             # duplicate row 0, whose identical scatter writes are benign.
             bkey = (slots, max_seq, self.chunk_tokens, "chunk_batched")
             if bkey not in jit_cache:
-                def chunk_batch_fn(p, cache, toks, pos0s, slots_arr):
+                def serve_chunk_batch(p, cache, toks, pos0s, slots_arr):
                     sl = jax.tree.map(
                         lambda x: jnp.take(x, slots_arr, axis=1), cache)
                     _, new_sl = model.prefill_chunk(p, sl, toks, pos0s)
                     return jax.tree.map(
                         lambda full, s: full.at[:, slots_arr].set(s),
                         cache, new_sl)
-                jit_cache[bkey] = jax.jit(chunk_batch_fn)
+                jit_cache[bkey] = jax.jit(serve_chunk_batch)
             self._chunk_batched = jit_cache[bkey]
             # prefix-cache restore/extract with a *traced* slot index: a
             # plain eager cache.at[:, slot, :L].set() bakes the slot in as
@@ -227,14 +251,14 @@ class ServingEngine:
             # admission stalls. One compile per prefix length L instead.
             pkey = (slots, max_seq, "prefix")
             if pkey not in jit_cache:
-                def restore_fn(cache, entry, slot):
+                def serve_prefix_restore(cache, entry, slot):
                     return jax.tree.map(
                         lambda full, ent: jax.lax.dynamic_update_slice(
                             full, ent[:, None].astype(full.dtype),
                             (0, slot) + (0,) * (full.ndim - 2)),
                         cache, entry)
 
-                def extract_fn(cache, slot, start, length):
+                def serve_prefix_extract(cache, slot, start, length):
                     # start is traced (the slice length is always one chunk,
                     # so a static start would recompile per boundary offset)
                     return jax.tree.map(
@@ -242,8 +266,9 @@ class ServingEngine:
                             jax.lax.dynamic_slice_in_dim(x, slot, 1, 1),
                             start, length, 2)[:, 0],
                         cache)
-                jit_cache[pkey] = (jax.jit(restore_fn),
-                                   jax.jit(extract_fn, static_argnums=3))
+                jit_cache[pkey] = (jax.jit(serve_prefix_restore),
+                                   jax.jit(serve_prefix_extract,
+                                           static_argnums=3))
             self._pc_restore, self._pc_extract = jit_cache[pkey]
         # speculative decode rides the same padding-safety gate as chunking
         # (verify writes candidate K/V at absolute positions and relies on
@@ -265,7 +290,7 @@ class ServingEngine:
         if self._spec_ok:
             vkey = (slots, max_seq, self.speculate, "verify")
             if vkey not in jit_cache:
-                def verify_fn(p, cache, toks, pos):
+                def serve_verify(p, cache, toks, pos):
                     # greedy argmax in-graph: the engine only needs the
                     # target's token choices, not (slots, K+1, V) f32 logits
                     # on the host every step
@@ -275,7 +300,7 @@ class ServingEngine:
                         logits[..., :model.cfg.vocab_size],
                         axis=-1).astype(jnp.int32)
                     return greedy, new_cache
-                jit_cache[vkey] = jax.jit(verify_fn)
+                jit_cache[vkey] = jax.jit(serve_verify)
             self._verify = jit_cache[vkey]
         # -- async decode loop state --------------------------------------
         self._stop = threading.Event()
@@ -329,7 +354,10 @@ class ServingEngine:
         for j, r in enumerate(grp):
             r.trace.open("prefill", mode="batched", group=len(grp))
             toks[j, :len(r.tokens)] = r.tokens
-        grp_cache = self._prefill(self.params, jnp.asarray(toks))
+        grp_cache = self._call(self._prefill, self.params, jnp.asarray(toks))
+        self.metrics["prefill_positions_computed"] += toks.size
+        self.metrics["prefill_positions_real"] += sum(len(r.tokens)
+                                                      for r in grp)
         slots_arr = jnp.asarray([r.slot for r in grp], jnp.int32)
         rows = jnp.arange(len(grp))
         self.cache = jax.tree.map(
@@ -349,32 +377,34 @@ class ServingEngine:
         state; the rest take a single padded batched prefill (per
         prompt-length group when padding is unsafe)."""
         batch: List[Request] = []
-        for slot in range(self.slots):
-            if self.active[slot] is not None:
-                continue
-            try:
-                r = self.queue.get_nowait()
-            except queue.Empty:
-                break
-            r.slot = slot
-            r.trace.close("queue_wait", replica=self.name, slot=slot)
-            if self.monitor is not None:
-                # queue-wait is an SLO surface of its own: load gauges count
-                # *requests* waiting, this measures how long they waited —
-                # long generations at low concurrency hurt here first
-                self.monitor.gauge(self.name, "queue_wait_s",
-                                   time.perf_counter() - r.submit_t)
-            # chunked admission for prompts longer than one chunk, or ones a
-            # prefix cache could serve (>= one chunk boundary); sub-chunk
-            # prompts can neither hit nor seed the cache, so they keep the
-            # fused padded batched prefill
-            if self._chunk_ok and (
-                    len(r.tokens) > self.chunk_tokens
-                    or (self.prefix_cache is not None
-                        and len(r.tokens) >= self.chunk_tokens)):
-                self._admit_chunked(r)
-            else:
-                batch.append(r)
+        with TraceAnnotation("serve.admit"):
+            for slot in range(self.slots):
+                if self.active[slot] is not None:
+                    continue
+                try:
+                    r = self.queue.get_nowait()
+                except queue.Empty:
+                    break
+                r.slot = slot
+                r.trace.close("queue_wait", replica=self.name, slot=slot)
+                if self.monitor is not None:
+                    # queue-wait is an SLO surface of its own: load gauges
+                    # count *requests* waiting, this measures how long they
+                    # waited — long generations at low concurrency hurt here
+                    # first
+                    self.monitor.gauge(self.name, "queue_wait_s",
+                                       time.perf_counter() - r.submit_t)
+                # chunked admission for prompts longer than one chunk, or
+                # ones a prefix cache could serve (>= one chunk boundary);
+                # sub-chunk prompts can neither hit nor seed the cache, so
+                # they keep the fused padded batched prefill
+                if self._chunk_ok and (
+                        len(r.tokens) > self.chunk_tokens
+                        or (self.prefix_cache is not None
+                            and len(r.tokens) >= self.chunk_tokens)):
+                    self._admit_chunked(r)
+                else:
+                    batch.append(r)
         if not batch:
             return
         if self._pad_ok:
@@ -386,7 +416,8 @@ class ServingEngine:
             groups = list(by_len.values())
         for grp in groups:
             try:
-                self._prefill_group(grp)
+                with TraceAnnotation("serve.prefill"):
+                    self._prefill_group(grp)
             except Exception as exc:
                 # fail just this group: the requests were already pulled off
                 # the queue, so an unhandled raise would strand them
@@ -409,9 +440,9 @@ class ServingEngine:
             covered, entry = self.prefix_cache.lookup(r.tokens)
             if covered:
                 try:
-                    self.cache = self._pc_restore(
-                        self.cache, jax.tree.map(jnp.asarray, entry),
-                        np.int32(r.slot))
+                    self.cache = self._call(
+                        self._pc_restore, self.cache,
+                        jax.tree.map(jnp.asarray, entry), np.int32(r.slot))
                     start = covered
                     self.metrics["prefix_hit_tokens"] += covered
                     span.annotate(prefix_hit_tokens=covered)
@@ -458,8 +489,8 @@ class ServingEngine:
             toks = np.zeros((1, c), np.int32)   # final partial chunk padded:
             toks[0, :end - start] = r.tokens[start:end]   # one compile per C
             try:
-                self.cache = self._chunk(
-                    self.params, self.cache, jnp.asarray(toks),
+                self.cache = self._call(
+                    self._chunk, self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray([start], jnp.int32), np.int32(slot))
             except Exception as exc:
                 del self._prefilling[slot]
@@ -471,6 +502,8 @@ class ServingEngine:
                     self.monitor.log(self.name, "prefill_error",
                                      error=repr(exc), requests=1)
                 continue
+            self.metrics["prefill_positions_computed"] += c
+            self.metrics["prefill_positions_real"] += end - start
             self._after_chunk(slot, start, end, r)
 
     def _prefill_chunks_batched(self, items):
@@ -495,9 +528,9 @@ class ServingEngine:
         pos0[len(items):] = pos0[0]
         slot_idx[len(items):] = slot_idx[0]
         try:
-            self.cache = self._chunk_batched(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(pos0), jnp.asarray(slot_idx))
+            self.cache = self._call(
+                self._chunk_batched, self.params, self.cache,
+                jnp.asarray(toks), jnp.asarray(pos0), jnp.asarray(slot_idx))
         except Exception as exc:
             # the batch failed as a unit: every participating request fails
             for slot, _start, _end, r in rows:
@@ -511,6 +544,9 @@ class ServingEngine:
                                  error=repr(exc), requests=len(rows))
             return
         self.metrics["prefill_chunk_batches"] += 1
+        self.metrics["prefill_positions_computed"] += toks.size
+        self.metrics["prefill_positions_real"] += sum(
+            end - start for _slot, start, end, _r in rows)
         for slot, start, end, r in rows:
             self._after_chunk(slot, start, end, r)
 
@@ -526,8 +562,8 @@ class ServingEngine:
             # the cache stores per-chunk slices: offer only this
             # chunk's [end-c, end) positions (the trie chain supplies
             # the rest on restore)
-            entry = self._pc_extract(self.cache, np.int32(slot),
-                                     np.int32(end - c), c)
+            entry = self._call(self._pc_extract, self.cache, np.int32(slot),
+                               np.int32(end - c), c)
             self.prefix_cache.insert(r.tokens[:end], entry)
         if end >= len(r.tokens):
             del self._prefilling[slot]
@@ -559,21 +595,25 @@ class ServingEngine:
         slots. Returns #active."""
         self._admit()
         if self._prefilling:
-            self._prefill_step()
+            with TraceAnnotation("serve.chunk"):
+                self._prefill_step()
         active = [i for i in range(self.slots)
                   if self.active[i] is not None and i not in self._prefilling]
-        if self.monitor is not None and (self._prefilling or self.queue.qsize()):
-            self.monitor.gauge(self.name, "prefill_backlog",
-                               self.prefill_backlog)
         if not active:
             return len(self._prefilling)
         if self._spec_ok:
             self._spec_step(active)
         else:
             self._decode_step(active)
-        if self.monitor is not None:
-            self.monitor.gauge(self.name, "queue_depth", self.load)
         return len(active) + len(self._prefilling)
+
+    def _call(self, program, *args):
+        """Call one of the engine's programs; the first call after a token
+        fetch closes the host gap that the fetch opened."""
+        if self._fetched_t is not None:
+            self.metrics["host_gap_s"] += time.perf_counter() - self._fetched_t
+            self._fetched_t = None
+        return program(*args)
 
     def _emit_token(self, i: int, r: Request, tok: int, now: float) -> bool:
         """Record one generated token for slot ``i`` — the single source of
@@ -606,25 +646,30 @@ class ServingEngine:
 
     def _decode_step(self, active: List[int]):
         """One fused single-token decode over ``active``."""
-        toks = np.zeros((self.slots, 1), np.int32)
-        # idle / still-prefilling rows decode a scratch token at position
-        # max_seq-1 (never written or attended by a real request: admission
-        # requires len+1 <= max_seq and decode stops at pos+1 >= max_seq),
-        # so the fused decode can't clobber a half-prefilled slot's cache
-        pos = np.full((self.slots,), self.max_seq - 1, np.int32)
-        for i in active:
-            r = self.active[i]
-            toks[i, 0] = (r.generated[-1] if r.generated
-                          else int(r.tokens[-1]))
-            pos[i] = max(int(self.pos[i]), 0)
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos))
-        next_tokens = np.asarray(jnp.argmax(logits[:, 0, :self.cfg.vocab_size],
-                                            axis=-1))
-        self.metrics["decode_steps"] += 1
-        now = time.perf_counter()
-        for i in active:
-            self._emit_token(i, self.active[i], int(next_tokens[i]), now)
+        with TraceAnnotation("serve.decode"):
+            toks = np.zeros((self.slots, 1), np.int32)
+            # idle / still-prefilling rows decode a scratch token at position
+            # max_seq-1 (never written or attended by a real request:
+            # admission requires len+1 <= max_seq and decode stops at
+            # pos+1 >= max_seq), so the fused decode can't clobber a
+            # half-prefilled slot's cache
+            pos = np.full((self.slots,), self.max_seq - 1, np.int32)
+            for i in active:
+                r = self.active[i]
+                toks[i, 0] = (r.generated[-1] if r.generated
+                              else int(r.tokens[-1]))
+                pos[i] = max(int(self.pos[i]), 0)
+            logits, self.cache = self._call(
+                self._decode, self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(pos))
+            greedy = jnp.argmax(logits[:, 0, :self.cfg.vocab_size], axis=-1)
+        with TraceAnnotation("serve.fetch"):
+            next_tokens = np.asarray(greedy)
+        now = self._fetched_t = time.perf_counter()
+        with TraceAnnotation("serve.emit"):
+            self.metrics["decode_steps"] += 1
+            for i in active:
+                self._emit_token(i, self.active[i], int(next_tokens[i]), now)
 
     def _spec_step(self, active: List[int]):
         """One speculative verify step over ``active``: the draft proposes
@@ -638,20 +683,30 @@ class ServingEngine:
         like the fused decode."""
         k = self.speculate
         items = [(i, self.active[i]) for i in active]
-        props = np.asarray(self.draft.propose(items, k), np.int32)
-        toks = np.zeros((self.slots, k + 1), np.int32)
-        pos = np.full((self.slots,), self.max_seq - 1, np.int32)
-        for row, (i, r) in enumerate(items):
-            toks[i, 0] = (r.generated[-1] if r.generated
-                          else int(r.tokens[-1]))
-            toks[i, 1:] = props[row]
-            pos[i] = max(int(self.pos[i]), 0)
-        greedy, self.cache = self._verify(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos))
-        greedy = np.asarray(greedy)                      # (slots, k+1)
+        with TraceAnnotation("serve.decode"):
+            props = np.asarray(self.draft.propose(items, k), np.int32)
+            toks = np.zeros((self.slots, k + 1), np.int32)
+            pos = np.full((self.slots,), self.max_seq - 1, np.int32)
+            for row, (i, r) in enumerate(items):
+                toks[i, 0] = (r.generated[-1] if r.generated
+                              else int(r.tokens[-1]))
+                toks[i, 1:] = props[row]
+                pos[i] = max(int(self.pos[i]), 0)
+            greedy, self.cache = self._call(
+                self._verify, self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(pos))
+        with TraceAnnotation("serve.fetch"):
+            greedy = np.asarray(greedy)                  # (slots, k+1)
+        now = self._fetched_t = time.perf_counter()
+        with TraceAnnotation("serve.emit"):
+            self._spec_emit(active, toks, greedy, now)
+
+    def _spec_emit(self, active: List[int], toks, greedy, now: float):
+        """Accept each slot's longest matching candidate prefix and emit
+        it; the bookkeeping after a verify step's fetch."""
+        k = self.speculate
         self.metrics["decode_steps"] += 1
         self.metrics["spec_steps"] += 1
-        now = time.perf_counter()
         accepted = emitted = 0
         for i in active:
             r = self.active[i]
@@ -680,6 +735,7 @@ class ServingEngine:
     # -- synchronous loop (tests / oracles) --------------------------------
     def run_until_idle(self, max_steps: int = 10_000):
         assert not self.running, "run_until_idle on a started engine"
+        self._fetched_t = None      # no host gap across separate runs
         steps = 0
         while (not self.queue.empty() or any(a is not None
                                              for a in self.active)):
@@ -695,6 +751,7 @@ class ServingEngine:
             return self
         self._stop.clear()
         self._killed = False
+        self._fetched_t = None
         self.heartbeat = time.monotonic()
         self._thread = threading.Thread(target=self._loop,
                                         name=f"{self.name}-decode",
@@ -720,7 +777,12 @@ class ServingEngine:
             # compile) must not read as a dead container to the health sweep
             self.heartbeat = time.monotonic()
             if n == 0:
-                self._wake.wait(timeout=0.005)
+                with TraceAnnotation("serve.wait"):
+                    t = time.perf_counter()
+                    self._wake.wait(timeout=0.005)
+                    if self._fetched_t is not None:
+                        # waiting for work is no part of the host gap
+                        self._fetched_t += time.perf_counter() - t
                 self._wake.clear()
 
     def _fail_inflight(self, exc: Exception):

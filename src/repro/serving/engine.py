@@ -8,11 +8,17 @@ finished ones free their slot — the serving analogue of short-lived
 containerized tools).
 
 The engine is asynchronous by design: ``start()`` launches the decode loop on
-a background thread that admits waiting requests via a single *padded batched
-prefill* (one ``prefill`` call for every newly admitted slot instead of one
-batch-1 call per request), and ``stop()`` signals it through a real
-``threading.Event``. The synchronous ``run_until_idle`` path is kept for
-deterministic single-threaded use (tests, oracles).
+a background thread that admits waiting requests between decode steps, and
+``stop()`` signals it through a real ``threading.Event``. The synchronous
+``run_until_idle`` path is kept for deterministic single-threaded use (tests,
+oracles).
+
+Where padding is safe, each admitted prompt is prefilled alone, its length
+padded to a bucket multiple, and never padded to ``slots`` rows: at published
+widths a prefill is bound by its compute, so a group padded to 16 rows costs
+about 16 times a lone prompt's row, while one more program dispatch per
+request is small beside it. Only at a toy size, where every call costs about
+its dispatch, would one padded call per admission group be the cheaper.
 
 With ``chunk_tokens`` set (padding-safe models only), long prompts are
 *chunk-prefilled*: the prompt enters the per-slot cache in chunk-sized
@@ -93,7 +99,7 @@ class Request:
 
 
 def _padding_safe(model, max_seq: int) -> bool:
-    """Right-padded batched prefill is exact only when every sub-layer is
+    """Right-padded prefill is exact only when every sub-layer is
     global attention at this ``max_seq``: decode overwrites cache position
     ``pos`` before attending, so pad garbage beyond the prompt is never read.
     Rolling (sliding-window) caches place the *last W of the padded length*
@@ -179,9 +185,9 @@ class ServingEngine:
         self._fetched_t: Optional[float] = None
         # jitted prefill/decode are shared across all engines with the same
         # (model, slots, max_seq): replicas and failover respawns then reuse
-        # one compile instead of paying it per replica. Prefill is jitted
-        # with the padded (slots, bucketed_len) shape so repeat admissions
-        # hit the compile cache instead of re-tracing.
+        # one compile instead of paying it per replica. Prefill sees the
+        # padded (1, bucketed_len) shape where padding is safe, so repeat
+        # admissions hit the compile cache instead of re-tracing.
         jit_cache = getattr(model, "_engine_jit_cache", None)
         if jit_cache is None:
             jit_cache = {}
@@ -338,43 +344,71 @@ class ServingEngine:
         return min(self.max_seq, ((n + b - 1) // b) * b)
 
     def _prefill_group(self, grp: List[Request]):
-        """One prefill call for a group of newly admitted requests. When
-        padding is safe, the batch dim is padded to ``slots`` and the length
-        to a bucket multiple, so the jitted prefill compiles once per bucket,
-        not once per request. Rolling/SSM/MoE groups are same-length and must
-        stay exact — with no pad rows — since length padding would wrap the
-        rolling cache (evicting real prompt positions) or feed pad tokens
+        """Prefill a group of newly admitted requests into their slots.
+
+        When padding is safe, each request takes a call of its own: one row,
+        its length padded to a bucket multiple, so the jitted prefill
+        compiles once per bucket whatever the group's size, and a request
+        that fails fails alone. Rows are not padded to ``slots``: at
+        published widths a pad row costs as much compute as a real one.
+        Rolling/SSM/MoE groups are same-length and take one exact call,
+        with no pad rows and no pad columns, since length padding would wrap
+        the rolling cache (evicting real prompt positions) or feed pad tokens
         into recurrent state, and pad rows would consume MoE expert
-        capacity."""
-        maxlen = max(len(r.tokens) for r in grp)
-        rows = self.slots if self._pad_ok else len(grp)
+        capacity; such a group fails as a unit."""
+        if not self._pad_ok:
+            for r in grp:
+                r.trace.open("prefill", mode="batched", group=len(grp))
+            self._prefill_rows(grp)
+            return
+        for r in grp:
+            r.trace.open("prefill", mode="single", group=len(grp))
+        for r in grp:
+            try:
+                self._prefill_rows([r])
+            except Exception as exc:
+                self._fail_prefill([r], exc)
+
+    def _prefill_rows(self, reqs: List[Request]):
+        """One ``serve_prefill`` call, a row per request, and the write of
+        each row into its request's slot."""
+        n = max(len(r.tokens) for r in reqs)
         if self._pad_ok:
-            maxlen = self._bucket_len(maxlen)
-        toks = np.zeros((rows, maxlen), np.int32)
-        for j, r in enumerate(grp):
-            r.trace.open("prefill", mode="batched", group=len(grp))
+            n = self._bucket_len(n)
+        toks = np.zeros((len(reqs), n), np.int32)
+        for j, r in enumerate(reqs):
             toks[j, :len(r.tokens)] = r.tokens
-        grp_cache = self._call(self._prefill, self.params, jnp.asarray(toks))
+        rows_cache = self._call(self._prefill, self.params, jnp.asarray(toks))
         self.metrics["prefill_positions_computed"] += toks.size
         self.metrics["prefill_positions_real"] += sum(len(r.tokens)
-                                                      for r in grp)
-        slots_arr = jnp.asarray([r.slot for r in grp], jnp.int32)
-        rows = jnp.arange(len(grp))
+                                                      for r in reqs)
+        slots_arr = jnp.asarray([r.slot for r in reqs], jnp.int32)
         self.cache = jax.tree.map(
-            lambda full, new: full.at[:, slots_arr].set(new[:, rows]),
-            self.cache, grp_cache)
+            lambda full, new: full.at[:, slots_arr].set(new),
+            self.cache, rows_cache)
         self.metrics["prefills"] += 1
-        self.metrics["prefill_requests"] += len(grp)
-        for r in grp:
+        self.metrics["prefill_requests"] += len(reqs)
+        for r in reqs:
             self.pos[r.slot] = len(r.tokens) - 1
             self.active[r.slot] = r
             r.trace.close("prefill", tokens=len(r.tokens))
             r.trace.open("decode")
 
+    def _fail_prefill(self, reqs: List[Request], exc: Exception):
+        """Fail requests whose prefill raised: they were already pulled off
+        the queue, so an unhandled raise would strand them."""
+        for r in reqs:
+            r.slot = -1
+            if not r.future.done():
+                r.future.set_exception(exc)
+        if self.monitor is not None:
+            self.monitor.log(self.name, "prefill_error",
+                             error=repr(exc), requests=len(reqs))
+
     def _admit(self):
         """Fill free slots from the queue: long prompts (and any prompt when
         a prefix cache may hold its head) enter the chunk-wise prefill
-        state; the rest take a single padded batched prefill (per
+        state; the rest are prefilled whole, as one admission group (per
         prompt-length group when padding is unsafe)."""
         batch: List[Request] = []
         with TraceAnnotation("serve.admit"):
@@ -397,7 +431,7 @@ class ServingEngine:
                 # chunked admission for prompts longer than one chunk, or
                 # ones a prefix cache could serve (>= one chunk boundary);
                 # sub-chunk prompts can neither hit nor seed the cache, so
-                # they keep the fused padded batched prefill
+                # they keep the whole-prompt prefill
                 if self._chunk_ok and (
                         len(r.tokens) > self.chunk_tokens
                         or (self.prefix_cache is not None
@@ -419,15 +453,7 @@ class ServingEngine:
                 with TraceAnnotation("serve.prefill"):
                     self._prefill_group(grp)
             except Exception as exc:
-                # fail just this group: the requests were already pulled off
-                # the queue, so an unhandled raise would strand them
-                for r in grp:
-                    r.slot = -1
-                    if not r.future.done():
-                        r.future.set_exception(exc)
-                if self.monitor is not None:
-                    self.monitor.log(self.name, "prefill_error",
-                                     error=repr(exc), requests=len(grp))
+                self._fail_prefill(grp, exc)
 
     # -- chunked prefill ---------------------------------------------------
     def _admit_chunked(self, r: Request):

@@ -180,7 +180,7 @@ class ModelDraft:
                                    jnp.asarray(toks), np.int32(slot))
         # padded prefill writes K/V beyond the prompt too, but those
         # positions are masked (kpos <= pos) until real tokens overwrite
-        # them — same argument as the engine's padded batched prefill
+        # them — same argument as the engine's padded prefill
         self._written[slot] = np.asarray(ctx, np.int64)
         self._req[slot] = r
 

@@ -37,11 +37,12 @@ def _prompts(cfg, lengths, seed=0):
 
 # -- prefill positions --------------------------------------------------------
 
-# (chunk_tokens, prompt lengths, positions computed): a padded group of 3
-# rows x the 32-position bucket; one prompt in three batch-1 chunks; two
-# prompts chunked together twice (3 rows x 16 each), then the longer alone
+# (chunk_tokens, prompt lengths, positions computed): a group of two, each
+# prompt alone in its own bucket (16 and 32 positions); one prompt in three
+# batch-1 chunks; two prompts chunked together twice (3 rows x 16 each),
+# then the longer alone
 PREFILL_CASES = {
-    "padded_group": (None, (5, 20), 3 * 32),
+    "padded_group": (None, (5, 20), 16 + 32),
     "batch1_chunk": (CHUNK, (40,), 3 * CHUNK),
     "batched_chunk": (CHUNK, (40, 20), 2 * 3 * CHUNK + CHUNK),
 }
@@ -60,7 +61,7 @@ def test_prefill_positions_counted_where_computed(served_model, case):
     assert m["prefill_positions_computed"] == computed
     assert m["prefill_positions_real"] == sum(lengths)
     if case == "padded_group":
-        assert (m["prefills"], m["prefill_chunks"]) == (1, 0)
+        assert (m["prefills"], m["prefill_chunks"]) == (2, 0)
     elif case == "batch1_chunk":
         assert (m["prefill_chunks"], m["prefill_chunk_batches"]) == (3, 0)
     else:

@@ -32,8 +32,9 @@ def _factory(model, params, monitor=None, slots=2, max_seq=96):
 # -- batched prefill ---------------------------------------------------------
 
 def test_batched_prefill_parity_with_oracle(served_model):
-    """Mixed-length prompts admitted in ONE padded prefill call must decode
-    exactly like the sequential oracle."""
+    """Mixed-length prompts admitted in one group, each prefilled alone at
+    its own padded length, must decode exactly like the sequential
+    oracle."""
     cfg, model, params = served_model
     eng = ServingEngine(model, params, slots=4, max_seq=96)
     rng = np.random.default_rng(7)
@@ -41,12 +42,79 @@ def test_batched_prefill_parity_with_oracle(served_model):
                for n in (4, 11, 6, 15)]
     futs = [eng.submit(p, max_new_tokens=5) for p in prompts]
     eng.run_until_idle()
-    # all four admitted at once -> exactly one prefill call
-    assert eng.metrics["prefills"] == 1
+    # all four admitted at once -> one prefill call per request
+    assert eng.metrics["prefills"] == 4
     assert eng.metrics["prefill_requests"] == 4
     for p, f in zip(prompts, futs):
         ref = greedy_generate(model, params, p, 5, 96)
         np.testing.assert_array_equal(f.result(), ref)
+
+
+def test_padding_safe_prefill_one_row_per_call(served_model):
+    """Admission groups of mixed sizes and lengths: every prefill call
+    carries one row, the prefill compiles once per length bucket whatever
+    the group sizes, and the tokens match the oracle."""
+    cfg, model, params = served_model
+    max_seq = 112          # an engine key no other test compiles
+    eng = ServingEngine(model, params, slots=3, max_seq=max_seq,
+                        prefill_bucket=16)
+    assert eng._pad_ok
+    rows, groups = [], []
+    prefill, prefill_group = eng._prefill, eng._prefill_group
+
+    def spy_prefill(p, toks):
+        rows.append(toks.shape)
+        return prefill(p, toks)
+
+    def spy_group(grp):
+        groups.append(len(grp))
+        return prefill_group(grp)
+    eng._prefill, eng._prefill_group = spy_prefill, spy_group
+    compiled = prefill._cache_size()
+    rng = np.random.default_rng(5)
+    lengths = (5, 20, 33, 12, 40, 7, 17, 31)
+    news = (3, 7, 2, 5, 4, 6, 2, 3)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in lengths]
+    futs = [eng.submit(p, max_new_tokens=k) for p, k in zip(prompts, news)]
+    eng.run_until_idle()
+    one = [eng.submit(prompts[3], max_new_tokens=2)]
+    eng.run_until_idle()
+    assert len(set(groups)) > 1 and sum(groups) == len(lengths) + 1
+    assert rows and all(r[0] == 1 for r in rows)
+    assert eng.metrics["prefills"] == len(rows) == len(lengths) + 1
+    assert prefill._cache_size() - compiled == len({r[1] for r in rows}) \
+        == len({eng._bucket_len(n) for n in lengths}) == 3
+    for p, k, f in zip(prompts + prompts[3:4], news + (2,), futs + one):
+        np.testing.assert_array_equal(
+            f.result(), greedy_generate(model, params, p, k, max_seq))
+
+
+def test_padding_safe_prefill_failure_fails_only_its_request(served_model):
+    """A prefill call that raises fails its own request; the others of its
+    admission group still go in and decode exactly."""
+    cfg, model, params = served_model
+    eng = ServingEngine(model, params, slots=3, max_seq=96)
+    prefill, calls = eng._prefill, []
+
+    def flaky(p, toks):
+        calls.append(toks.shape)
+        if len(calls) == 2:
+            raise RuntimeError("injected prefill fault")
+        return prefill(p, toks)
+    eng._prefill = flaky
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (6, 14, 9)]
+    futs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+    eng.run_until_idle()
+    assert len(calls) == 3
+    with pytest.raises(RuntimeError, match="injected"):
+        futs[1].result(timeout=0)
+    for i in (0, 2):
+        np.testing.assert_array_equal(
+            futs[i].result(timeout=0),
+            greedy_generate(model, params, prompts[i], 4, 96))
+    assert eng.metrics["prefill_requests"] == 2
+    assert all(a is None for a in eng.active)
 
 
 def test_rolling_cache_model_groups_by_length():

@@ -2,7 +2,8 @@
 
 Once the window has closed, the program's state freed and the peak memory
 read, a sample of the requests due in the window that the served path
-finished, drawn from the seed and always holding the longest, is run
+finished, drawn from the seed and always holding the longest and at least
+one request of every replica, is run
 through the family's plain float32 reference, once over each prompt and
 its served tokens. Each served token was the program's greedy choice; the
 number compared is the widest gap by which a served token's reference
@@ -10,7 +11,8 @@ logit lies below the reference's best at that position.
 
 Beside it, as counts with the limit 0: requests due in the window that
 failed, that never finished, and sampled ones that served another number of
-tokens than they asked for (the mixes ignore end-of-sequence).
+tokens than they asked for (the mixes ignore end-of-sequence); and the
+replicas whose requests were checked, which must be all of them.
 """
 from __future__ import annotations
 
@@ -19,10 +21,17 @@ import numpy as np
 MIN_SAMPLE = 3
 
 
+def replica(sent) -> int:
+    """The index of the replica that served a request (0 where the
+    harness did not tag it)."""
+    return getattr(sent.request, "replica", 0)
+
+
 def sample(sent_in_window, seed: int, served_tokens: int) -> list:
-    """The longest finished request, then others in an order drawn from
-    the seed, until ``served_tokens`` tokens and ``MIN_SAMPLE`` requests
-    are in (or every finished one is)."""
+    """The longest finished request; then, in an order drawn from the
+    seed, the first of each replica not yet in, then others, until
+    ``served_tokens`` tokens and ``MIN_SAMPLE`` requests are in (or every
+    finished one is)."""
     done = [s for s in sent_in_window
             if not s.failed and s.request is not None
             and s.request.done_t is not None]
@@ -32,13 +41,19 @@ def sample(sent_in_window, seed: int, served_tokens: int) -> list:
                                        + len(s.request.generated), s.index))
     rest = [s for s in done if s is not longest]
     rng = np.random.default_rng([int(seed) % (1 << 64), 5])
-    order = rng.permutation(len(rest)) if rest else []
-    out = [longest]
-    for i in order:
-        if (sum(len(s.request.generated) for s in out) >= served_tokens
+    order = [rest[i] for i in rng.permutation(len(rest))] if rest else []
+    out, seen = [longest], {replica(longest)}
+    for s in order:
+        if replica(s) not in seen:
+            out.append(s)
+            seen.add(replica(s))
+    picked = {id(s) for s in out}
+    for s in order:
+        if (sum(len(x.request.generated) for x in out) >= served_tokens
                 and len(out) >= MIN_SAMPLE):
             break
-        out.append(rest[i])
+        if id(s) not in picked:
+            out.append(s)
     return out
 
 
@@ -90,6 +105,8 @@ def checks(run, ref, config: dict, seed: int, served_tokens: int,
         "short_outputs": {"value": sum(len(s.request.generated) != s.max_new
                                        for s in picked), "max": 0},
         "checked_requests": {"value": len(picked), "min": MIN_SAMPLE},
+        "checked_replicas": {"value": len({replica(s) for s in picked}),
+                             "min": run.chips},
     }
     g = reference_gaps(ref, config["model"], seed, picked, modes) \
         if picked else {}

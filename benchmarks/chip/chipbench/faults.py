@@ -20,6 +20,21 @@ def altered_token(patch) -> None:
     patch(ServingEngine, "_emit_token", wrong)
 
 
+def altered_token_on_replica1(patch) -> None:
+    """Every second token is replaced where it is made, on the replica
+    named ``replica1`` alone (a cell of one replica has none): a fault of
+    one chip among several, which only a check of every replica's work
+    is sure to see."""
+    from repro.serving.engine import ServingEngine
+    emit = ServingEngine._emit_token
+
+    def wrong(self, i, r, tok, now):
+        if self.name == "replica1" and len(r.generated) % 2 == 1:
+            tok = (tok + 1) % self.cfg.vocab_size
+        return emit(self, i, r, tok, now)
+    patch(ServingEngine, "_emit_token", wrong)
+
+
 def unchanged_cache(patch) -> None:
     """The decode step returns the cache it was given, unwritten."""
     from repro.serving.engine import ServingEngine
@@ -32,4 +47,8 @@ def unchanged_cache(patch) -> None:
     patch(ServingEngine, "__init__", patched)
 
 
-FAULTS = {"altered_token": altered_token, "unchanged_cache": unchanged_cache}
+FAULTS = {"altered_token": altered_token,
+          "altered_token_on_replica1": altered_token_on_replica1,
+          "unchanged_cache": unchanged_cache}
+# faults that only a cell of several replicas can have
+MULTI_REPLICA = {"altered_token_on_replica1"}

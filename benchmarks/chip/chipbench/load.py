@@ -1,9 +1,12 @@
 """The load driver: one thread that sends a mix's requests to ``submit``.
 
-The loop is closed: each client sends its next request when its last one
-completes. Each request is timed from when it was *due*, the completion
-that released its client. The time it was actually sent is kept beside it,
-so a driver that ran late shows as lateness and not as a slow server.
+A closed loop (a mix with ``clients``): each client sends its next request
+when its last one completes, and the request is *due* at the completion
+that released its client. An open loop (a mix with ``arrivals``): request
+k is due at ``t0`` plus its scheduled offset (``Stream.due``) and is sent
+then, whatever the server is doing. Each request is timed from when it was
+due. The time it was actually sent is kept beside it, so a driver that ran
+late shows as lateness and not as a slow server.
 """
 from __future__ import annotations
 
@@ -22,7 +25,7 @@ clock = time.perf_counter
 @dataclasses.dataclass
 class Sent:
     index: int                  # request k of the stream
-    client: int
+    client: int                 # -1 in an open loop
     due: float
     prompt: np.ndarray
     max_new: int
@@ -57,7 +60,8 @@ class Driver:
 
     def start(self, t0: float) -> "Driver":
         self.t0 = t0
-        self._thread = threading.Thread(target=self._closed,
+        loop = self._open if "arrivals" in self.mix else self._closed
+        self._thread = threading.Thread(target=loop,
                                         name="chipbench-load",
                                         daemon=True)
         self._thread.start()
@@ -107,6 +111,15 @@ class Driver:
         done = self._done
         s.request.future.add_done_callback(lambda _f, s=s: done.put(s))
         return s
+
+    def _open(self) -> None:
+        k = 0
+        while True:
+            due = self.t0 + self.stream.due(k)
+            if self._stop.wait(max(0.0, due - clock())):
+                break
+            self._send(k, -1, due)
+            k += 1
 
     def _closed(self) -> None:
         clients = int(self.mix["clients"])

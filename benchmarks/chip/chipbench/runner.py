@@ -1,8 +1,10 @@
 """One run of one cell, in the order the benchmark's contract sets.
 
 1. Find the chip (or stop before any result), set the compile cache.
-2. Make the weights from the seed on the device, build the served path.
-3. Warm every shape the mix can produce; start the load; ramp.
+2. Make the weights from the seed on each of the cell's chips, build the
+   served path: one replica on each chip, behind the program's router.
+3. Warm every shape the mix can produce, on every replica at once; start
+   the load; ramp.
 4. Open the window: read the counters; with ``--trace 1`` take a profiler
    trace of a few seconds inside it. Close it: read the counters, stop the
    load, wait for what was sent, read the peak memory.
@@ -75,14 +77,17 @@ def serve_window(cell: spec.Cell, opts: Options, devices, t_process: float,
                  compiles: device_mod.CompileLog, *, warm: bool = True,
                  layout: spec.Layout = spec.Layout(),
                  trace_dir: Path = PROFILE_DIR) -> RunRecord:
-    """Steps 2-4: serve the cell's mix through the program and measure;
-    the program's state is freed on return."""
+    """Steps 2-4: serve the cell's mix through the program on the cell's
+    chips, one replica on each, and measure; the program's state is freed
+    on return."""
     import jax
     config, mix, dep = cell.config, cell.traffic, cell.config["deployment"]
     ref, cost = spec.family_modules(config, layout)
-    used = list(devices)[:1]
+    used = list(devices)[:cell.chips]
     t = clock()
-    params = ref.program_params(config["model"], opts.seed, used[0])
+    # the same seed on every chip: every replica serves the same model
+    params = [ref.program_params(config["model"], opts.seed, d)
+              for d in used]
     jax.block_until_ready(params)
     t_weights = clock() - t
     model, rs = serving.build(config, params, used, traced=opts.trace)
@@ -100,7 +105,7 @@ def serve_window(cell: spec.Cell, opts: Options, devices, t_process: float,
     driver.start(t_start)
     try:
         time.sleep(max(0.0, t_start + float(mix["ramp_s"]) - clock()))
-        c_open, t_open = counters(rs.engines), clock()
+        (c_open, r_open), t_open = counters(rs.engines), clock()
         n_open = compiles.snapshot()[0]
         setup_s = t_open - t_process
         summary = None
@@ -110,7 +115,7 @@ def serve_window(cell: spec.Cell, opts: Options, devices, t_process: float,
             span = min(TRACE_SECONDS, 0.25 * opts.seconds)
             xplane = profile_mod.capture(str(trace_dir), span)
         time.sleep(max(0.0, t_open + opts.seconds - clock()))
-        c_close, t_close = counters(rs.engines), clock()
+        (c_close, r_close), t_close = counters(rs.engines), clock()
         n_close = compiles.snapshot()[0]
         driver.stop()
         drained = driver.drain(DRAIN_S)
@@ -127,7 +132,8 @@ def serve_window(cell: spec.Cell, opts: Options, devices, t_process: float,
         f"(warm)")
     log(f"compiles inside the window: {n_close - n_open}")
     log(f"window {t_close - t_open:.3f} s; {len(driver.sent)} requests "
-        f"sent; all finished after close: {drained}")
+        f"sent over {len(used)} replicas; all finished after close: "
+        f"{drained}")
     log(f"device: {json.dumps(dev)}")
     record = RunRecord(
         cell=cell.name, seconds=opts.seconds, t_open=t_open,
@@ -136,7 +142,8 @@ def serve_window(cell: spec.Cell, opts: Options, devices, t_process: float,
         deployment=dep, cost=cost,
         peaks=device_mod.peaks(devices[0].device_kind)
         if devices[0].platform == "tpu" else None,
-        device=dev, traced=opts.trace, profile=summary)
+        device=dev, traced=opts.trace, profile=summary, chips=len(used),
+        replicas_open=r_open, replicas_close=r_close)
     del rs, model, driver
     gc.collect()
     log(f"device bytes still held after the served path was freed: "

@@ -2,12 +2,15 @@
 
 ``build`` makes it as ``repro.launch.serve.build_replicaset`` does, from a
 configuration file: ``build_model`` -> ``ServingEngine`` factory ->
-``ReplicaSet``, with the weights the benchmark made from the seed
-(``reference/<family>.program_params``) in place of the program's own
-initializer. ``warm`` drives each engine through every shape the mix can
-produce before the load starts.
+``ReplicaSet``, one replica on each device it is given, with the weights the
+benchmark made from the seed (``reference/<family>.program_params``) in
+place of the program's own initializer. Requests go through the program's
+own router (``ReplicaSet.submit_request``, least load). ``warm`` drives each
+engine through every shape the mix can produce before the load starts.
 """
 from __future__ import annotations
+
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -44,26 +47,43 @@ class SpanSink:
         pass
 
 
-def build(config: dict, params, devices, *, traced: bool):
-    """(model, ReplicaSet) for the configuration's deployment: one
-    replica on the first device."""
+def tag_replica(engine, i: int) -> None:
+    """Each request the engine takes records the replica's index as
+    ``request.replica``, so the check can sample every replica's work.
+    Routing is left to the program."""
+    submit = engine.submit_request
+
+    def tagged(*a, **kw):
+        r = submit(*a, **kw)
+        r.replica = i
+        return r
+    engine.submit_request = tagged
+
+
+def build(config: dict, params: list, devices, *, traced: bool):
+    """(model, ReplicaSet) for the configuration's deployment on
+    ``devices``: one replica on each, replica i serving ``params[i]``
+    (made on device i)."""
     from repro.models.model import build_model
     from repro.serving.engine import ServingEngine
     from repro.serving.replica import ReplicaSet
 
     dep = config["deployment"]
+    devices = list(devices)
     model = build_model(model_config(config))
-    check_layout(model, params)
+    check_layout(model, params[0])
     sink = SpanSink() if traced else None
 
     def factory(i: int, devs=None):
-        return ServingEngine(
-            model, params, slots=dep["slots"], max_seq=dep["max_seq"],
+        eng = ServingEngine(
+            model, params[i], slots=dep["slots"], max_seq=dep["max_seq"],
             name=f"replica{i}", devices=devs,
             prefill_bucket=dep.get("prefill_bucket", 16),
             chunk_tokens=dep.get("chunk_tokens") or None, recorder=sink)
+        tag_replica(eng, i)
+        return eng
 
-    rs = ReplicaSet(factory, replicas=1, devices=list(devices)[:1],
+    rs = ReplicaSet(factory, replicas=len(devices), devices=devices,
                     recorder=sink)
     return model, rs
 
@@ -91,15 +111,23 @@ def warm_plan(bounds: tuple, dep: dict) -> list:
 
 def warm(engines, bounds: tuple, dep: dict, vocab: int) -> int:
     """Run ``warm_plan`` synchronously on every engine (before the pool's
-    decode loops start); returns the requests it served."""
-    rng = np.random.default_rng(0)
-    served = 0
-    for eng in engines:
-        for group in warm_plan(bounds, dep):
+    decode loops start), each engine on a thread of its own, so that
+    replicas compile or load their programs side by side; returns the
+    requests served."""
+    plan = warm_plan(bounds, dep)
+
+    def one(eng) -> int:
+        rng = np.random.default_rng(0)
+        served = 0
+        for group in plan:
             reqs = [eng.submit_request(rng.integers(1, vocab, size=n),
                                        max_new_tokens=1) for n in group]
             eng.run_until_idle()
             for r in reqs:
                 r.future.result(timeout=0)
             served += len(reqs)
-    return served
+        return served
+
+    with ThreadPoolExecutor(max_workers=len(engines),
+                            thread_name_prefix="chipbench-warm") as ex:
+        return sum(ex.map(one, engines))

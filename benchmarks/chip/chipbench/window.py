@@ -5,7 +5,8 @@ A reader (``metrics/<name>.py``) is a function ``read(run)`` of one
 read, and the harness then leaves that metric out of the line.
 
 Counters are the engines' cumulative ``metrics``, read at the window's open
-and close; a window's count is their difference. A tail is the tail of all
+and close; a window's count is their difference, summed over the engines
+(``count``) or per engine (``count_per_replica``). A tail is the tail of all
 requests the definition selects, failed ones included where the metric
 says so.
 """
@@ -31,12 +32,14 @@ def percentile(values, q: float) -> Optional[float]:
     return float(v[lo] + (rank - lo) * (v[hi] - v[lo]))
 
 
-def counters(engines) -> dict:
+def counters(engines) -> tuple:
+    """(the engines' counters summed, each engine's own), read once."""
+    per = [dict(e.metrics) for e in engines]
     out: dict = {}
-    for e in engines:
-        for k, v in e.metrics.items():
+    for m in per:
+        for k, v in m.items():
             out[k] = out.get(k, 0) + v
-    return out
+    return out, per
 
 
 @dataclasses.dataclass
@@ -56,6 +59,9 @@ class RunRecord:
     device: dict                # the result's device record
     traced: bool = False
     profile: Optional[dict] = None   # profile.summarize() of the trace
+    chips: int = 1                   # chips the cell used, one replica each
+    replicas_open: list = dataclasses.field(default_factory=list)
+    replicas_close: list = dataclasses.field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -64,6 +70,11 @@ class RunRecord:
     def count(self, name: str) -> int:
         return self.counters_close.get(name, 0) - \
             self.counters_open.get(name, 0)
+
+    def count_per_replica(self, name: str) -> List:
+        """The window's count of one counter, for each engine."""
+        return [c.get(name, 0) - o.get(name, 0)
+                for o, c in zip(self.replicas_open, self.replicas_close)]
 
     def inside(self, t) -> bool:
         return t is not None and self.t_open <= t < self.t_close

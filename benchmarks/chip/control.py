@@ -3,7 +3,7 @@
 process (the benchmark's own runs never run this).
 
     python3 benchmarks/chip/control.py --workload <cell> --seeds 1,2,3 \
-        --seconds 10 [--fault altered_token|unchanged_cache] \
+        --seconds 10 [--fault <a name in chipbench/faults.py>] \
         [--out chiprun_out/control.json]
 
 For each seed it serves a window of the cell's own mix through the program,
@@ -84,7 +84,7 @@ def main(argv=None) -> int:
                                   default=None),
                "program_all_correct": all(r["program_correct"]
                                           for r in rows),
-               "device": device.record(devices, devices[:1])}
+               "device": device.record(devices, devices[:cell.chips])}
     if not a.fault:
         summary["control_min"] = min(r["control"] for r in rows)
         summary["control_any_correct"] = any(r["control_correct"]

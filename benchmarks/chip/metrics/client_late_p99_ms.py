@@ -1,5 +1,6 @@
 """Load generator: 99th percentile, over the requests due in the window,
-of how late each was sent (send time less due time)."""
+of how late each was sent: send time less due time (an open loop's
+scheduled arrival, a closed loop's releasing completion)."""
 from chipbench.window import percentile
 
 

@@ -1,5 +1,5 @@
-"""Model step: the operations the window's work needs, over what the chip
-could do in the window at its bf16 peak, in percent.
+"""Model step: the operations the window's work needs, over what the chips
+the cell used could do in the window at their bf16 peak, in percent.
 
 The work: the prompt of every request whose first token came in the window
 (causal attention, the head at the last position) and every generated
@@ -17,5 +17,5 @@ def read(run):
     n, ctx = run.token_contexts()
     flops += n * cost.decode_flops(model, 0.0) + \
         cost.attention_flops(model, ctx)
-    peak = run.peaks.flops_bf16 * run.window_s
+    peak = run.peaks.flops_bf16 * run.chips * run.window_s
     return 100.0 * flops / peak if flops else None

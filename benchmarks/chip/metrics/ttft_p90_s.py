@@ -1,6 +1,7 @@
 """90th percentile, over every request due in the window, of its first
-token's time less the time it was due (a closed loop's request is due when
-the completion that released its client came). A request that failed or
+token's time less the time it was due: an open loop's request is due at
+its scheduled arrival, a closed loop's when the completion that released
+its client came. A request that failed or
 never gave a token counts as infinitely late; when the percentile falls on
 one, the metric is left out and the check's failure counts say why."""
 import math

@@ -1,5 +1,6 @@
 """The traffic generator: seed determinism, length bounds, the same work
-for every seed, and the driver's due-time bookkeeping."""
+and the same arrival schedule for every seed, and the driver's due-time
+bookkeeping in a closed and an open loop."""
 import json
 import threading
 import time
@@ -14,6 +15,15 @@ from chipbench import traffic
 from chipbench.load import Driver
 
 MIXES = sorted(p.stem for p in (BENCH_DIR / "traffic").glob("*.json"))
+OPEN_MIXES = [m for m in MIXES if "arrivals" in json.loads(
+    (BENCH_DIR / "traffic" / f"{m}.json").read_text())]
+# an open mix at a cell's size, beside the benchmark's own open mixes
+GAMMA_CV2 = {"arrivals": {"dist": "gamma", "cv": 2.0, "rate_rps": 4.2},
+             "sizes": 1024,
+             "prompt_tokens": {"dist": "lognormal", "median": 384,
+                               "sigma": 0.8, "min": 32, "max": 1536},
+             "output_tokens": {"dist": "lognormal", "median": 128,
+                               "sigma": 0.8, "min": 8, "max": 511}}
 BIG_SEED = 2 ** 31 + 2 ** 33 + 7        # more than 32 bits
 
 
@@ -33,7 +43,8 @@ def test_same_seed_same_requests(name):
 @pytest.mark.parametrize("name", MIXES)
 def test_every_seed_gets_the_same_lengths(name):
     """The seed draws the token ids alone: request k has the same length,
-    and each client's first request the same share, for every seed."""
+    and each client's first request the same share (a closed loop) or
+    request k the same due time (an open loop), for every seed."""
     m = mix(name)
     streams = [traffic.Stream(m, seed, 64000, 2048)
                for seed in (1, 2, BIG_SEED)]
@@ -41,8 +52,12 @@ def test_every_seed_gets_the_same_lengths(name):
         reqs = [s.request(k) for s in streams]
         assert len({(len(ids), new) for ids, new in reqs}) == 1
         assert not np.array_equal(reqs[0][0], reqs[1][0])
-    shares = [s.first_shares(m["clients"]) for s in streams]
-    assert all(np.array_equal(shares[0], x) for x in shares[1:])
+    if "clients" in m:
+        shares = [s.first_shares(m["clients"]) for s in streams]
+        assert all(np.array_equal(shares[0], x) for x in shares[1:])
+    else:
+        for k in (0, 1, 17, 300, 5000):
+            assert len({s.due(k) for s in streams}) == 1
 
 
 @pytest.mark.parametrize("name", MIXES)
@@ -120,3 +135,59 @@ def test_closed_loop_times_each_request_from_its_release():
     # staggered first requests ask for a share of their drawn output
     firsts = [s.max_new for s in d.sent[:3]]
     assert firsts != [stream.request(k)[1] for k in range(3)]
+
+
+@pytest.mark.parametrize("name", OPEN_MIXES + ["gamma_cv2"])
+def test_open_loop_schedule_has_its_rate_and_burstiness(name):
+    """One schedule for every seed, whose gaps have a mean of exactly
+    1 / rate and a coefficient of variation near the mix's; it repeats
+    after ``sizes`` requests."""
+    m = GAMMA_CV2 if name == "gamma_cv2" else mix(name)
+    a, b = (traffic.Stream(m, seed, 64000, 2048) for seed in (3, BIG_SEED))
+    n = int(m["sizes"])
+    due = np.array([a.due(k) for k in range(2 * n + 1)])
+    assert np.array_equal(due, [b.due(k) for k in range(2 * n + 1)])
+    gaps = np.diff(due)
+    rate, cv = m["arrivals"]["rate_rps"], m["arrivals"]["cv"]
+    assert due[0] == 0.0 and np.all(gaps >= 0)
+    assert gaps[:n].mean() == pytest.approx(1.0 / rate, rel=1e-9)
+    assert gaps.std() / gaps.mean() == pytest.approx(cv, rel=0.05)
+    assert np.allclose(gaps[:n], gaps[n:])
+    with pytest.raises(ValueError):
+        traffic.gaps({"dist": "poisson", "rate_rps": 1.0}, 8)
+
+
+def test_open_loop_sends_each_request_when_it_is_due():
+    """Request k is due at t0 plus its scheduled offset, whatever the
+    server does (here it never finishes anything), and is sent then."""
+    m = {"arrivals": {"dist": "gamma", "cv": 2.0, "rate_rps": 40.0},
+         "sizes": 16,
+         "prompt_tokens": {"dist": "lognormal", "median": 6, "sigma": 0.3,
+                           "min": 4, "max": 8},
+         "output_tokens": {"dist": "lognormal", "median": 4, "sigma": 0.4,
+                           "min": 2, "max": 6}}
+    stream = traffic.Stream(m, BIG_SEED, 100, 64)
+    got = []
+
+    def submit(tokens, max_new_tokens, eos_id):
+        got.append((time.perf_counter(), len(tokens), max_new_tokens))
+        return FakeRequest(tokens, max_new_tokens)
+
+    t0 = time.perf_counter() + 0.05
+    d = Driver(m, stream, submit).start(t0)
+    time.sleep(0.75)
+    d.stop()
+    sent = d.sent
+    assert len(sent) == len(got) >= 20
+    assert [s.index for s in sent] == list(range(len(sent)))
+    for s, (t, plen, new) in zip(sent, got):
+        assert s.client == -1
+        assert s.due == t0 + stream.due(s.index)
+        assert s.due <= s.submit <= t + 1e-3
+        assert (plen, new) == (len(stream.request(s.index)[0]),
+                               stream.request(s.index)[1])
+    # the sender kept up (a busy host may delay a send now and then)
+    assert np.median([s.submit - s.due for s in sent]) < 0.01
+    # none finished, yet requests kept coming: the loop is open
+    assert not any(s.done for s in sent)
+    assert sent[-1].due <= time.perf_counter()

@@ -116,23 +116,19 @@ def test_mfu_and_bandwidth_from_the_cost_model():
     assert read("mfu", run_of([])) is None      # never a share of 0
 
 
-def test_prefill_row_fill_from_spans():
-    qw = Span("queue_wait", 100.5, 101.0, replica="r0")
-    # a padded group of 2 (of 4 slots) and a lone request: 3 real, 8 rows
-    padded = [Span("prefill", 101.0, 101.1, mode="batched", group=2),
-              Span("prefill", 101.0, 101.1, mode="batched", group=2),
-              Span("prefill", 103.0, 103.1, mode="batched", group=1)]
-    # chunk steps: two slots together (4 rows), then one alone (1 row)
-    c1 = Span("prefill", 104.0, 106.0, mode="chunked",
-              events=[(104.0, "chunk", {}), (105.0, "chunk", {})])
-    c2 = Span("prefill", 104.0, 106.0, mode="chunked",
-              events=[(104.0002, "chunk", {})])
-    sent = [request(8, 100.5, 102, 103, 2, spans=[qw, s])
-            for s in padded + [c1, c2]]
-    run = run_of(sent)
-    # real rows 3 + 3 chunks; computed 4 + 4 (padded), 4 + 1 (chunks)
-    assert read("prefill_row_fill", run) == pytest.approx(100 * 6 / 13)
-    assert read("queue_wait_p90_s", run) == pytest.approx(0.5)
+def test_queue_wait_from_the_spans_of_every_replica():
+    """Each request's ``queue_wait`` span ends where its replica's slot
+    took it; the tail is over every replica's requests."""
+    waits = [0.1, 0.2, 0.3, 0.4, 1.0]
+    sent = [request(8, 101.0, 102, 103, 2,
+                    spans=[Span("queue_wait", 101.0, 101.0 + w,
+                                replica=f"replica{i % 2}")])
+            for i, w in enumerate(waits)]
+    # a wait that ended before the window opened is not in its tail
+    sent.append(request(8, 90.0, 95, 96, 2,
+                        spans=[Span("queue_wait", 90.0, 99.0)]))
+    assert read("queue_wait_p90_s", run_of(sent)) == pytest.approx(
+        float(np.percentile(waits, 90)))
 
 
 def test_device_idle_share_from_the_trace_summary():
